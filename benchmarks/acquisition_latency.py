@@ -38,6 +38,7 @@ import time
 
 from benchmarks.bench_util import emit
 
+from repro import compile_cache
 from repro.core import Measurement, ScaleType, StudyConfig, Trial
 from repro.core.study import Study
 from repro.pythia.gp_bandit import GPBanditPolicy
@@ -162,6 +163,7 @@ def bench_sparse_scenario(n: int, count: int, *, repeats: int,
 
 
 def main() -> int:
+    compile_cache.enable()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--repeats", type=int, default=5)
     parser.add_argument("--warmup", type=int, default=2)

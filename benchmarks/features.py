@@ -7,6 +7,7 @@ runs for real; the benchmark reports per-feature latency and PASS/FAIL.
 
 from benchmarks.bench_util import emit, timeit
 
+from repro import compile_cache
 from repro.core import (
     AutomatedStoppingConfig,
     Measurement,
@@ -103,6 +104,7 @@ def bench_parallel_trials(server) -> str:
 
 
 def main() -> None:
+    compile_cache.enable()
     server = DefaultVizierServer()
     for name, fn in [
         ("table1.multi_objective", bench_multi_objective),

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from benchmarks.bench_util import emit, timeit
 
+from repro import compile_cache
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.gram import matern52_gram_pallas
@@ -18,6 +19,7 @@ RNG = np.random.RandomState(0)
 
 
 def main() -> None:
+    compile_cache.enable()
     # gram
     n, m, d = 256, 256, 32
     x1 = jnp.asarray(RNG.randn(n, d), jnp.float32)

@@ -33,6 +33,7 @@ import numpy as np
 
 from benchmarks.bench_util import emit
 
+from repro import compile_cache
 from repro.core import Measurement, StudyConfig, Trial
 from repro.core.pareto import hypervolume, pareto_frontier_indices
 from repro.core.study import Study
@@ -112,6 +113,7 @@ def run_loop(problem: str, algorithm: str) -> dict:
 
 
 def main() -> int:
+    compile_cache.enable()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--out", default=OUT_PATH)
     args = parser.parse_args()

@@ -10,6 +10,7 @@ import time
 
 from benchmarks.bench_util import emit
 
+from repro import compile_cache
 from repro.core import ScaleType, StudyConfig
 from repro.service import DefaultVizierServer, VizierClient
 
@@ -83,6 +84,7 @@ def run_study(n_workers: int, budget: int, crash: bool = False) -> float:
 
 
 def main() -> None:
+    compile_cache.enable()
     for workers, crash in [(1, False), (4, False), (4, True)]:
         best, wall, n = run_study(workers, budget=24, crash=crash)
         emit(f"sec5.parallel.workers={workers}.crash={crash}",

@@ -10,6 +10,7 @@ import sys
 import traceback
 
 from benchmarks import bench_util  # noqa: F401  (sets sys.path)
+from repro import compile_cache
 
 MODULES = [
     ("features", "paper Table 1 feature matrix, exercised end-to-end"),
@@ -27,6 +28,7 @@ MODULES = [
 
 
 def main() -> None:
+    compile_cache.enable()
     p = argparse.ArgumentParser()
     p.add_argument("--only", default=None,
                    help="comma-separated subset of benchmark modules")

@@ -16,6 +16,7 @@ import time
 
 from benchmarks.bench_util import emit
 
+from repro import compile_cache
 from repro.core import Measurement, ScaleType, StudyConfig, Trial
 from repro.service import (
     DefaultVizierServer,
@@ -356,6 +357,7 @@ def bench_crash_recovery(tmpdir="/tmp/bench_crash.db") -> None:
 
 
 def main() -> None:
+    compile_cache.enable()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--batched", action="store_true",
                         help="run the BatchSuggestTrials coalescing scenario")

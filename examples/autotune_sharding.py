@@ -11,6 +11,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.core import TrialState
 from repro.service import DefaultVizierServer, VizierClient
 from repro.tuning import shardtune_study_config
@@ -31,6 +32,7 @@ def fake_roofline(params) -> float:
 
 
 def main():
+    compile_cache.enable()
     server = DefaultVizierServer()
     config = shardtune_study_config()
     client = VizierClient.load_or_create_study(

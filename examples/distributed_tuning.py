@@ -18,6 +18,7 @@ import threading
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.configs import get_arch
 from repro.core import AutomatedStoppingConfig, ScaleType, StudyConfig, TrialState
 from repro.service import DistributedVizierServer, VizierBatchClient, VizierClient
@@ -39,6 +40,7 @@ def make_study_config() -> StudyConfig:
 
 
 def main():
+    compile_cache.enable()
     server = DistributedVizierServer()  # API service + separate Pythia service
     print(f"API server: {server.address}; Pythia server: {server.pythia_address}")
 

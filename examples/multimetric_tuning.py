@@ -14,6 +14,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.core import ScaleType, StudyConfig
 from repro.service import DefaultVizierServer, VizierClient
 
@@ -29,6 +30,7 @@ def evaluate(params) -> dict:
 
 
 def main():
+    compile_cache.enable()
     config = StudyConfig()
     root = config.search_space.select_root()
     root.add_float_param("width", 64, 1024, scale_type=ScaleType.LOG)

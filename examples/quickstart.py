@@ -8,6 +8,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.core import ScaleType, StudyConfig
 from repro.service import DefaultVizierServer, VizierClient
 
@@ -20,6 +21,7 @@ def evaluate_trial(params) -> float:
 
 
 def main():
+    compile_cache.enable()
     server = DefaultVizierServer(host="127.0.0.1")
 
     config = StudyConfig()

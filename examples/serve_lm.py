@@ -9,12 +9,14 @@ sys.path.insert(0, "src")
 
 import jax
 
+from repro import compile_cache
 from repro.configs import get_arch
 from repro.models import build_model
 from repro.serve import DecodeEngine, Request
 
 
 def main():
+    compile_cache.enable()
     cfg = get_arch("zamba2_1p2b", reduced=True)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

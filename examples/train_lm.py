@@ -13,6 +13,7 @@ sys.path.insert(0, "src")
 
 import jax
 
+from repro import compile_cache
 from repro.configs import get_arch
 from repro.distributed.sharding import ShardingCtx, make_rules
 from repro.launch.mesh import make_local_mesh
@@ -23,6 +24,7 @@ from repro.train.train_loop import LoopConfig, train
 
 
 def main():
+    compile_cache.enable()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=200)
     args = p.parse_args()

@@ -9,6 +9,7 @@ prior landscape instead of sampling blind.
     PYTHONPATH=src python examples/transfer_tuning.py
 """
 
+from repro import compile_cache
 from repro.core import ScaleType, StudyConfig
 from repro.service import DefaultVizierServer, VizierClient
 
@@ -33,6 +34,7 @@ def evaluate(params, *, lr_opt: float) -> float:
 
 
 def main() -> None:
+    compile_cache.enable()
     server = DefaultVizierServer()
 
     # 1. An earlier tuning run on a related task (e.g. the smaller model).

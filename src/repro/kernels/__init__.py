@@ -4,8 +4,8 @@ Layout per kernel:
   <name>.py   pl.pallas_call + BlockSpec implementation (TPU target; validated
               on CPU via interpret=True)
   ref.py      pure-jnp oracles (the correctness ground truth)
-  ops.py      jit'd dispatch wrappers: XLA path by default on CPU, Pallas path
-              on TPU (or interpret=True when forced)
+  ops.py      jit'd dispatch wrappers: Pallas path on TPU, XLA path on every
+              other backend (tests pass impl="pallas_interpret" explicitly)
 
 Kernels:
   gram            Matérn-5/2 Gram matrix + fused Gram·vector (K^T·alpha

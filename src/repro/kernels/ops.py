@@ -1,15 +1,15 @@
 """jit'd dispatch wrappers over the Pallas kernels.
 
-Default behavior:
+Default behavior (``impl="auto"``):
   * on TPU backends -> Pallas kernel path
-  * on CPU (this container) -> XLA reference path (fast, compiles everywhere)
-  * force the Pallas path under interpret=True with REPRO_FORCE_PALLAS=1 or
-    the explicit ``impl=`` argument (tests do this for kernel validation).
+  * on every other backend -> XLA reference path
+Tests select a path explicitly with ``impl=`` ("pallas_interpret" runs the
+Pallas kernels on the CPU).
 """
 
 from __future__ import annotations
 
-import os
+import functools
 from typing import Literal, Optional
 
 import jax
@@ -21,8 +21,6 @@ Impl = Literal["auto", "xla", "pallas", "pallas_interpret"]
 
 
 def _default_impl() -> str:
-    if os.environ.get("REPRO_FORCE_PALLAS"):
-        return "pallas_interpret"
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
@@ -71,11 +69,30 @@ def matern52_gram(
         return jnp.concatenate(strips, axis=1)
     if impl == "xla":
         return ref.matern52_gram(x1, x2, amplitude)
+    return _gram_pallas(x1, x2, jnp.asarray(amplitude, jnp.float32),
+                        impl == "pallas_interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gram_pallas(x1, x2, amplitude, interpret):
+    """The Pallas Gram with a VJP: the GP fit differentiates through it."""
     from repro.kernels.gram import matern52_gram_pallas
 
-    return matern52_gram_pallas(
-        x1, x2, jnp.asarray(amplitude), interpret=(impl == "pallas_interpret")
-    )
+    return matern52_gram_pallas(x1, x2, amplitude, interpret=interpret)
+
+
+def _gram_pallas_fwd(x1, x2, amplitude, interpret):
+    return _gram_pallas(x1, x2, amplitude, interpret), (x1, x2, amplitude)
+
+
+def _gram_pallas_bwd(interpret, residuals, g):
+    # XLA-only piece: the backward pass is the VJP of the jnp reference, so
+    # the kernel path's gradients are exactly the reference path's.
+    _, vjp = jax.vjp(ref.matern52_gram, *residuals)
+    return vjp(g)
+
+
+_gram_pallas.defvjp(_gram_pallas_fwd, _gram_pallas_bwd)
 
 
 def matern52_gram_matvec(

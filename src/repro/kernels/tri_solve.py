@@ -9,16 +9,21 @@ m×m ``LB`` factor with one update vector. Both stay on-device.
 forward substitution: the grid walks BLOCK_K-column strips of B (one strip
 per program, L resident in VMEM across the strip); within a strip the row
 dimension advances in RB=8-row blocks (the f32 sublane height) — one
-(RB, M) × (M, BLOCK_K) MXU contraction folds the already-solved prefix into
-the block's right-hand side, then the RB×RB diagonal block is solved with a
-statically unrolled substitution. Transposed solves (L^T x = b) are handled
+(RB, M) × (M, BLOCK_K) MXU contraction (full f32 precision) folds the
+already-solved prefix into the block's right-hand side, then the RB×RB
+diagonal block is solved with a statically unrolled substitution whose
+columns come out of the row block through lane masks: the TPU lowering
+accepts dynamic offsets only along rows, in RB-aligned blocks. Transposed
+solves (L^T x = b) are handled
 by the ops.py wrapper with the flip trick — reverse both axes of L and the
 rows of b, solve forward, reverse back — so one kernel serves both.
 
 ``cholupdate_pallas`` computes chol(L L^T + v v^T) with the classic column
 sweep: for each column k a Givens-style rotation (c, s) derived from the
 diagonal and v[k] updates the column and the remainder of v — O(m^2) total,
-a single grid-less program with L in VMEM.
+a single grid-less program with the factor in VMEM. The kernel sweeps the
+rows of U = L^T (the wrapper transposes in and out), so column k is read
+and written as an RB-aligned row block with a sublane mask.
 
 Padding: wrappers pad m up to a lane-aligned multiple with an IDENTITY
 diagonal block (and zero right-hand-side rows / update entries), so padded
@@ -40,29 +45,38 @@ RB = 8          # row-block height (f32 sublane)
 
 
 def _tri_solve_kernel(l_ref, b_ref, out_ref):
-    """One BLOCK_K-column strip of X with L X = B, L lower-triangular."""
-    M = l_ref.shape[0]
+    """One BLOCK_K-column strip of X with L X = B, L lower-triangular.
 
-    def row_block(rb, X):
-        start = rb * RB
-        rows = pl.load(l_ref, (pl.ds(start, RB), slice(None)))  # (RB, M)
+    Every dynamic access is an RB-aligned row block: the diagonal block's
+    columns are pulled out of the row block with lane masks, never with a
+    slice at a dynamic lane offset (which the TPU lowering refuses).
+    """
+    M = l_ref.shape[0]
+    out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (RB, M), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (RB, 1), 0)
+
+    def row_block(rb, carry):
+        start = pl.multiple_of(rb * RB, RB)
+        rows = l_ref[pl.ds(start, RB), :]                       # (RB, M)
         # fold the solved prefix: X rows >= start are still zero, so the
         # full-width contraction only picks up columns < start
         S = jax.lax.dot_general(
-            rows, X, (((1,), (0,)), ((), ())),
+            rows, out_ref[...], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)                 # (RB, K)
-        bblk = pl.load(b_ref, (pl.ds(start, RB), slice(None))) - S
-        diag = jax.lax.dynamic_slice(rows, (0, start), (RB, RB))
-        xblk = jnp.zeros_like(bblk)
-        for i in range(RB):  # static unroll: RB sequential pivots
-            ri = diag[i]     # (RB,); entries past i are zero in xblk
-            xi = (bblk[i] - ri @ xblk) / ri[i]
-            xblk = xblk.at[i].set(xi)
-        return jax.lax.dynamic_update_slice(X, xblk, (start, 0))
+        rhs = b_ref[pl.ds(start, RB), :] - S
+        xblk = jnp.zeros_like(rhs)
+        for j in range(RB):  # static unroll: RB sequential pivots
+            col = jnp.sum(jnp.where(lane == start + j, rows, 0.0),
+                          axis=1, keepdims=True)                # (RB, 1)
+            xj = rhs[j:j + 1, :] / col[j:j + 1, :]              # (1, K)
+            rhs = rhs - col * xj  # eliminate x_j from the rows below
+            xblk = jnp.where(sub == j, xj, xblk)
+        out_ref[pl.ds(start, RB), :] = xblk
+        return carry
 
-    X = jax.lax.fori_loop(
-        0, M // RB, row_block, jnp.zeros(out_ref.shape, jnp.float32))
-    out_ref[...] = X
+    jax.lax.fori_loop(0, M // RB, row_block, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -95,24 +109,32 @@ def tri_solve_pallas(
     return out[:m, :k]
 
 
-def _cholupdate_kernel(l_ref, v_ref, out_ref):
-    """Column sweep of the rank-1 update, in place over out_ref."""
-    M = l_ref.shape[0]
-    out_ref[...] = l_ref[...].astype(jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0)
+def _cholupdate_kernel(u_ref, v_ref, out_ref):
+    """Column sweep of the rank-1 update on U = L^T, in place over out_ref.
+
+    Column k of L is row k of U, so the sweep reads and writes RB-aligned
+    row blocks and picks row k out with a sublane mask; v is a (1, M) row.
+    """
+    M = u_ref.shape[0]
+    out_ref[...] = u_ref[...].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, M), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (RB, M), 0)
 
     def body(k, v):
-        col = pl.load(out_ref, (slice(None), pl.ds(k, 1)))   # (M, 1)
-        Lkk = jax.lax.dynamic_slice(col, (k, 0), (1, 1))
-        vk = jax.lax.dynamic_slice(v, (k, 0), (1, 1))
+        base = pl.multiple_of((k // RB) * RB, RB)
+        blk = out_ref[pl.ds(base, RB), :]                       # (RB, M)
+        at_row = sub == k - base
+        row = jnp.sum(jnp.where(at_row, blk, 0.0), axis=0, keepdims=True)
+        at_k = lane == k
+        Lkk = jnp.sum(jnp.where(at_k, row, 0.0), axis=1, keepdims=True)
+        vk = jnp.sum(jnp.where(at_k, v, 0.0), axis=1, keepdims=True)
         r = jnp.sqrt(Lkk * Lkk + vk * vk)
         c = r / Lkk
         s = vk / Lkk
-        below = rows > k
-        newcol = jnp.where(rows == k, r,
-                           jnp.where(below, (col + s * v) / c, col))
-        pl.store(out_ref, (slice(None), pl.ds(k, 1)), newcol)
-        return jnp.where(below, c * v - s * newcol, v)
+        below = lane > k
+        newrow = jnp.where(at_k, r, jnp.where(below, (row + s * v) / c, row))
+        out_ref[pl.ds(base, RB), :] = jnp.where(at_row, newrow, blk)
+        return jnp.where(below, c * v - s * newrow, v)
 
     jax.lax.fori_loop(0, M, body, v_ref[...].astype(jnp.float32))
 
@@ -128,17 +150,17 @@ def cholupdate_pallas(
     if pad_m:
         eye_tail = (jnp.arange(m + pad_m) >= m).astype(jnp.float32)
         Lp = Lp + jnp.diag(eye_tail)  # identity block rotates by identity
-    vp = jnp.pad(v.astype(jnp.float32), (0, pad_m)).reshape(-1, 1)
+    vp = jnp.pad(v.astype(jnp.float32), (0, pad_m)).reshape(1, -1)
     mp = m + pad_m
 
     out = pl.pallas_call(
         _cholupdate_kernel,
         in_specs=[
             pl.BlockSpec((mp, mp), lambda: (0, 0)),
-            pl.BlockSpec((mp, 1), lambda: (0, 0)),
+            pl.BlockSpec((1, mp), lambda: (0, 0)),
         ],
         out_specs=pl.BlockSpec((mp, mp), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, mp), jnp.float32),
         interpret=interpret,
-    )(Lp, vp)
-    return out[:m, :m]
+    )(Lp.T, vp)
+    return out.T[:m, :m]

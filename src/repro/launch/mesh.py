@@ -1,15 +1,14 @@
 """Production mesh definition (multi-pod dry-run contract).
 
 A FUNCTION, not a module-level constant: importing this module never touches
-jax device state. Axis types go through repro.compat so the module imports
-on JAX versions without jax.sharding.AxisType.
+jax device state.
 """
 
 from __future__ import annotations
 
 import jax
-
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

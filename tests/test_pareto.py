@@ -1,10 +1,7 @@
 """Pareto utilities: frontier invariants (hypothesis) + hypervolume."""
 
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.pareto import (
     crowding_distance,

@@ -14,10 +14,7 @@ import json
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Measurement, ScaleType, StudyConfig, Trial
 from repro.core.metadata import Metadata, MetadataDelta, Namespace

@@ -10,10 +10,7 @@ operations (bucket padding kills the per-(n, m) retraces).
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Measurement, ScaleType, StudyConfig, Trial, TrialState
 from repro.core.study import Study

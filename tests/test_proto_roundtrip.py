@@ -1,9 +1,6 @@
 """Wire-format roundtrips for Trials/Measurements/StudyConfigs (hypothesis)."""
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     Measurement,

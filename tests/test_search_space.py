@@ -10,10 +10,7 @@ import math
 import random
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     ParameterConfig,

@@ -11,10 +11,7 @@ engine kernel compiles at most once across shape-stable operations.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Measurement, StudyConfig, Trial
 from repro.core.study import Study

@@ -11,10 +11,7 @@ ride the single GetTrialsMulti frame — frame counts pinned).
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Measurement, ScaleType, StudyConfig, Trial
 from repro.core.metadata import Namespace

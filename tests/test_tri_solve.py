@@ -11,10 +11,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis wheel; see shim docstring
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.tri_solve import (
