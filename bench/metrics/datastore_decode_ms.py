@@ -1,0 +1,17 @@
+"""Datastore: time a worker batch spends turning stored trial blobs into
+trials (the ``vizier.datastore.decode`` spans: msgpack and from_proto), per
+suggest op served."""
+
+from bench.lib import spans
+
+UNIT, BETTER, SOURCE = "ms", "lower", "program_span"
+LAYER = "datastore"
+MOVES = "suggestions_per_s"
+
+
+def read(ctx):
+    w = spans.window(ctx)
+    if w is None:
+        return None
+    return w.per_served_op_ms(
+        sum(w.self_ns(r) for r in w.in_batches("vizier.datastore.decode")))

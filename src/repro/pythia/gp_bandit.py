@@ -34,13 +34,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.metadata import Metadata, MetadataDelta
 from repro.core.pareto import default_reference_point, pareto_frontier_indices
 from repro.core.study import TrialSuggestion
@@ -264,7 +264,6 @@ class FitInfo:
     warm: bool
     converged: bool
     diverged: bool
-    seconds: float
 
 
 class GaussianProcessBandit:
@@ -318,7 +317,6 @@ class GaussianProcessBandit:
         ``init`` (optional) is a PolicyState.fit_init() dict: raw params plus
         Adam moments and step count; the optimizer resumes mid-trajectory.
         """
-        t_wall = time.perf_counter()
         n, d = np.asarray(x).shape
         bucket = train_bucket(n)
         xb = np.zeros((bucket, d), np.float32)
@@ -395,7 +393,6 @@ class GaussianProcessBandit:
         self.last_fit = FitInfo(
             result=result, raw=traj_raw, m=traj_m, v=traj_v, t=traj_t,
             steps_run=steps, warm=warm, converged=converged, diverged=diverged,
-            seconds=time.perf_counter() - t_wall,
         )
         return result
 
@@ -493,7 +490,6 @@ class MultiFitInfo:
     warm: bool
     converged: bool
     diverged: bool
-    seconds: float
 
 
 class MultiMetricGP:
@@ -542,7 +538,6 @@ class MultiMetricGP:
         (optional) is ``PolicyState.metric_fit_init()``: per-metric raw
         params + Adam moments and the shared step count.
         """
-        t_wall = time.perf_counter()
         n, d = np.asarray(x).shape
         bucket = train_bucket(n)
         xb = np.zeros((bucket, d), np.float32)
@@ -627,7 +622,7 @@ class MultiMetricGP:
             ms=_unstack_tree(traj_m, self.k),
             vs=_unstack_tree(traj_v, self.k),
             t=traj_t, steps_run=steps, warm=warm, converged=converged,
-            diverged=diverged, seconds=time.perf_counter() - t_wall,
+            diverged=diverged,
         )
         return self.last_fit.results
 
@@ -873,7 +868,6 @@ class GPBanditPolicy(Policy):
         self._op_count = 0
         # observability for tests/benchmarks (mirrors
         # SerializableDesignerPolicy.last_restore_was_incremental)
-        self.last_fit_seconds: float = 0.0
         self.last_fit_steps: int = 0
         self.last_fit_warm: bool = False
         self.last_transfer_levels: int = 0
@@ -929,10 +923,15 @@ class GPBanditPolicy(Policy):
         return np.vstack([glob, local])
 
     def suggest(self, request: SuggestRequest) -> SuggestDecision:
+        with tracing.span("vizier.policy.suggest"):
+            return self._suggest(request)
+
+    def _suggest(self, request: SuggestRequest) -> SuggestDecision:
         config = request.study_config
         converter = TrialToArrayConverter(config.search_space)
         completed = self._supporter.CompletedTrials(request.study_guid)
-        x, y_all = trials_to_xy(completed, config, converter)
+        with tracing.span("vizier.policy.featurize"):
+            x, y_all = trials_to_xy(completed, config, converter)
         op_nonce = self._op_count
         self._op_count += 1
 
@@ -940,8 +939,7 @@ class GPBanditPolicy(Policy):
         self.last_transfer_levels = len(priors)
         # reset per-operation observability: a priors-only suggest performs
         # no current-study fit and must not report the previous one's
-        self.last_fit_seconds, self.last_fit_steps, self.last_fit_warm = \
-            0.0, 0, False
+        self.last_fit_steps, self.last_fit_warm = 0, False
         self.last_prior_levels_reused = 0
 
         if x.shape[0] < self._min_completed and not priors:
@@ -959,8 +957,9 @@ class GPBanditPolicy(Policy):
         # pending trials are loaded up front: the top level's factorization
         # reserves rank-1 headroom for their fantasies + the batch members
         pending = self._supporter.ActiveTrials(request.study_guid)
-        fantasy_x = converter.to_features(
-            [t.parameters for t in pending]) if pending else None
+        with tracing.span("vizier.policy.featurize"):
+            fantasy_x = converter.to_features(
+                [t.parameters for t in pending]) if pending else None
         n_pend = 0 if fantasy_x is None else len(fantasy_x)
         # Acquisition RNG: seeding by completed count ALONE meant consecutive
         # suggest ops at an unchanged completed count replayed the identical
@@ -978,92 +977,99 @@ class GPBanditPolicy(Policy):
         has_current = x.shape[0] >= 1
         headroom = n_pend + request.count
 
-        prior_fps = {name: int(px.shape[0]) for name, px, _py in priors}
-        reusable: List[Dict] = []
-        if self._warm_start and priors:
-            reusable = load_prior_levels(
-                request.study_metadata, dim=converter.dim,
-                priors=[(name, int(px.shape[0])) for name, px, _py in priors])
-        stack = StackedResidualGP(dim=converter.dim, seed=self._seed)
-        for i, (_name, px, py) in enumerate(priors):
-            top_prior = (i == len(priors) - 1) and not has_current
-            stack.fit_level(
-                px, _zscore(py),
-                raw=reusable[i] if i < len(reusable) else None,
-                capacity=px.shape[0] + headroom if top_prior else None)
-        self.last_prior_levels_reused = min(len(reusable), len(priors))
+        with tracing.span("vizier.policy.fit") as fit:
+            prior_fps = {name: int(px.shape[0]) for name, px, _py in priors}
+            reusable: List[Dict] = []
+            if self._warm_start and priors:
+                reusable = load_prior_levels(
+                    request.study_metadata, dim=converter.dim,
+                    priors=[(name, int(px.shape[0]))
+                            for name, px, _py in priors])
+            stack = StackedResidualGP(dim=converter.dim, seed=self._seed)
+            for i, (_name, px, py) in enumerate(priors):
+                top_prior = (i == len(priors) - 1) and not has_current
+                stack.fit_level(
+                    px, _zscore(py),
+                    raw=reusable[i] if i < len(reusable) else None,
+                    capacity=px.shape[0] + headroom if top_prior else None)
+            self.last_prior_levels_reused = min(len(reusable), len(priors))
 
-        fit_info = None
-        if has_current:
-            yn = _zscore(y_all[:, 0])
-            state = None
-            if self._warm_start:
-                state = load_state(request.study_metadata, dim=converter.dim,
-                                   num_trials=x.shape[0],
-                                   prior_fingerprints=prior_fps)
-            stack.fit_level(
-                x, yn, init=state.fit_init() if state is not None else None,
-                capacity=x.shape[0] + headroom)
-            fit_info = stack.last_fit
-            self.last_fit_seconds = fit_info.seconds
-            self.last_fit_steps = fit_info.steps_run
-            self.last_fit_warm = fit_info.warm
-        # acquisition works on the TOP level (the current study's residual GP
-        # when any current trials exist, else the deepest prior level); the
-        # levels below contribute a fixed mean shift.
-        top = stack.levels[-1]
-        self.last_sparse = isinstance(top.posterior, SparsePosterior)
-        raw = top.raw
-        n_below = stack.depth - 1
-        xs = np.asarray(top.x, np.float64)
-        ys = np.asarray(top.y, np.float64)
-        mu_xs = stack.mean(xs, below=n_below).astype(np.float64)
+            fit_info = None
+            if has_current:
+                yn = _zscore(y_all[:, 0])
+                state = None
+                if self._warm_start:
+                    state = load_state(request.study_metadata,
+                                       dim=converter.dim,
+                                       num_trials=x.shape[0],
+                                       prior_fingerprints=prior_fps)
+                stack.fit_level(
+                    x, yn, init=state.fit_init() if state is not None else None,
+                    capacity=x.shape[0] + headroom)
+                fit_info = stack.last_fit
+                self.last_fit_steps = fit_info.steps_run
+                self.last_fit_warm = fit_info.warm
+            fit.add(steps=self.last_fit_steps)
+        with tracing.span("vizier.policy.acquire"):
+            # acquisition works on the TOP level (the current study's
+            # residual GP when any current trials exist, else the deepest
+            # prior level); the levels below contribute a fixed mean shift.
+            top = stack.levels[-1]
+            self.last_sparse = isinstance(top.posterior, SparsePosterior)
+            raw = top.raw
+            n_below = stack.depth - 1
+            xs = np.asarray(top.x, np.float64)
+            ys = np.asarray(top.y, np.float64)
+            mu_xs = stack.mean(xs, below=n_below).astype(np.float64)
 
-        # one candidate pool per operation (incumbent = best STACKED value,
-        # not best residual); pending-trial dedup with the empty-pool
-        # fallback — a pending trial at every candidate must degrade to the
-        # unfiltered pool, never to an argmax over zero candidates
-        incumbent = xs[int(np.argmax(ys + mu_xs))]
-        pool = self._draw_pool(rng, converter.dim, incumbent)
-        fantasize = fantasy_x is not None and n_pend > 0 and (
-            config.observation_noise != ObservationNoise.HIGH
-        )
-        if fantasize:
-            d = np.linalg.norm(pool[:, None, :] - fantasy_x[None], axis=-1)
-            filtered = pool[np.min(d, axis=1) > 1e-3]
-            if len(filtered):
-                pool = filtered
-        pool_mu = stack.mean(pool, below=n_below) if n_below else \
-            np.zeros((len(pool),), np.float32)
+            # one candidate pool per operation (incumbent = best STACKED
+            # value, not best residual); pending-trial dedup with the
+            # empty-pool fallback — a pending trial at every candidate must
+            # degrade to the unfiltered pool, never to an argmax over zero
+            # candidates
+            incumbent = xs[int(np.argmax(ys + mu_xs))]
+            pool = self._draw_pool(rng, converter.dim, incumbent)
+            fantasize = fantasy_x is not None and n_pend > 0 and (
+                config.observation_noise != ObservationNoise.HIGH
+            )
+            if fantasize:
+                d = np.linalg.norm(pool[:, None, :] - fantasy_x[None], axis=-1)
+                filtered = pool[np.min(d, axis=1) > 1e-3]
+                if len(filtered):
+                    pool = filtered
+            pool_mu = stack.mean(pool, below=n_below) if n_below else \
+                np.zeros((len(pool),), np.float32)
 
-        beta = DEFAULT_UCB_BETA
-        y_pend = None
-        if fantasize:
-            # pending outcomes fantasized from the current posterior; UCB is
-            # linear in the mean, so averaging scores over F fantasy vectors
-            # equals scoring once at the fantasy-averaged outcomes
+            beta = DEFAULT_UCB_BETA
+            y_pend = None
+            if fantasize:
+                # pending outcomes fantasized from the current posterior; UCB
+                # is linear in the mean, so averaging scores over F fantasy
+                # vectors equals scoring once at the fantasy-averaged outcomes
+                if self._use_engine:
+                    mean_p, std_p = top.posterior.query(fantasy_x)
+                else:
+                    mp, sp = _posterior(raw, jnp.asarray(xs, jnp.float32),
+                                        jnp.asarray(ys, jnp.float32),
+                                        jnp.asarray(fantasy_x, jnp.float32))
+                    mean_p, std_p = np.asarray(mp), np.asarray(sp)
+                eps = rng.randn(self._n_fantasies, n_pend)
+                y_pend = mean_p + std_p * eps.mean(axis=0)
+
             if self._use_engine:
-                mean_p, std_p = top.posterior.query(fantasy_x)
+                picks = self._suggest_engine(top.posterior, pool, pool_mu, beta,
+                                             fantasy_x if fantasize else None,
+                                             y_pend, request.count)
             else:
-                mp, sp = _posterior(raw, jnp.asarray(xs, jnp.float32),
-                                    jnp.asarray(ys, jnp.float32),
-                                    jnp.asarray(fantasy_x, jnp.float32))
-                mean_p, std_p = np.asarray(mp), np.asarray(sp)
-            eps = rng.randn(self._n_fantasies, n_pend)
-            y_pend = mean_p + std_p * eps.mean(axis=0)
-
-        if self._use_engine:
-            picks = self._suggest_engine(top.posterior, pool, pool_mu, beta,
-                                         fantasy_x if fantasize else None,
-                                         y_pend, request.count)
-        else:
-            picks = self._suggest_legacy(raw, xs, ys, pool, pool_mu, beta,
-                                         fantasy_x if fantasize else None,
-                                         y_pend, request.count)
-        suggestions = [
-            TrialSuggestion(parameters=converter.to_parameters(p[None, :])[0])
-            for p in picks
-        ]
+                picks = self._suggest_legacy(raw, xs, ys, pool, pool_mu, beta,
+                                             fantasy_x if fantasize else None,
+                                             y_pend, request.count)
+        with tracing.span("vizier.policy.featurize"):
+            suggestions = [
+                TrialSuggestion(
+                    parameters=converter.to_parameters(p[None, :])[0])
+                for p in picks
+            ]
 
         if self._warm_start and fit_info is not None:
             # persist the fit checkpoint so the next (stateless) invocation
@@ -1110,8 +1116,9 @@ class GPBanditPolicy(Policy):
         posterior means via ``append_pool_member``.
         """
         pending = self._supporter.ActiveTrials(request.study_guid)
-        fantasy_x = converter.to_features(
-            [t.parameters for t in pending]) if pending else None
+        with tracing.span("vizier.policy.featurize"):
+            fantasy_x = converter.to_features(
+                [t.parameters for t in pending]) if pending else None
         n_pend = 0 if fantasy_x is None else len(fantasy_x)
         # same acquisition-RNG nonce as the single-objective path (see
         # suggest()): deterministic per observed snapshot + op index
@@ -1127,94 +1134,99 @@ class GPBanditPolicy(Policy):
         # wide-range metric cannot drown the others in the scalarization
         yz = np.stack([_zscore(y_all[:, j]) for j in range(k)], axis=1)
 
-        state = None
-        if self._warm_start:
-            state = load_metric_states(
-                request.study_metadata, dim=converter.dim, num_trials=n,
-                metric_names=metric_names)
-        gp = MultiMetricGP(dim=converter.dim, k=k, seed=self._seed)
-        init = state.metric_fit_init() if state is not None else None
-        sparse = n > SPARSE_THRESHOLD
-        if sparse:
-            if init is not None:
-                gp.fit_steps = min(gp.fit_steps, SPARSE_WARM_FIT_STEPS)
-            idx = _fit_subsample_idx(n)
-            raws = gp.fit(x[idx], yz[idx], init=init)
-        else:
-            raws = gp.fit(x, yz, init=init)
-        fit_info = gp.last_fit
-        self.last_fit_seconds = fit_info.seconds
-        self.last_fit_steps = fit_info.steps_run
-        self.last_fit_warm = fit_info.warm
-        self.last_sparse = sparse
-
-        # one posterior per metric over the SAME design rows and capacity:
-        # identical bucket shapes -> the engine kernels compiled for metric 0
-        # serve metrics 1..k-1 (and every single-objective study) unchanged
-        posts: List = []
-        for j in range(k):
+        with tracing.span("vizier.policy.fit") as fit:
+            state = None
+            if self._warm_start:
+                state = load_metric_states(
+                    request.study_metadata, dim=converter.dim, num_trials=n,
+                    metric_names=metric_names)
+            gp = MultiMetricGP(dim=converter.dim, k=k, seed=self._seed)
+            init = state.metric_fit_init() if state is not None else None
+            sparse = n > SPARSE_THRESHOLD
             if sparse:
-                posts.append(SparsePosterior(
-                    raws[j], x, yz[:, j], n_inducing=N_INDUCING,
-                    seed=self._seed, capacity=n + headroom))
+                if init is not None:
+                    gp.fit_steps = min(gp.fit_steps, SPARSE_WARM_FIT_STEPS)
+                idx = _fit_subsample_idx(n)
+                raws = gp.fit(x[idx], yz[idx], init=init)
             else:
-                posts.append(CholeskyPosterior(
-                    raws[j], x, yz[:, j], capacity=n + headroom))
+                raws = gp.fit(x, yz, init=init)
+            fit_info = gp.last_fit
+            self.last_fit_steps = fit_info.steps_run
+            self.last_fit_warm = fit_info.warm
+            self.last_sparse = sparse
 
-        # incumbent frontier + reference point from the OBSERVED (z-scored)
-        # objectives; the pool sharpens around a balanced frontier member
-        front_idx = pareto_frontier_indices(yz)
-        ref = default_reference_point(yz)                     # (k,)
-        front = yz[front_idx]
-        incumbent = x[front_idx[int(np.argmax(front.sum(axis=1)))]]
-        pool = self._draw_pool(rng, converter.dim, incumbent)
+            # one posterior per metric over the SAME design rows and
+            # capacity: identical bucket shapes -> the engine kernels
+            # compiled for metric 0 serve metrics 1..k-1 (and every
+            # single-objective study) unchanged
+            posts: List = []
+            for j in range(k):
+                if sparse:
+                    posts.append(SparsePosterior(
+                        raws[j], x, yz[:, j], n_inducing=N_INDUCING,
+                        seed=self._seed, capacity=n + headroom))
+                else:
+                    posts.append(CholeskyPosterior(
+                        raws[j], x, yz[:, j], capacity=n + headroom))
+            fit.add(steps=self.last_fit_steps)
 
-        fantasize = fantasy_x is not None and n_pend > 0 and (
-            config.observation_noise != ObservationNoise.HIGH
-        )
-        if fantasize:
-            d = np.linalg.norm(pool[:, None, :] - fantasy_x[None], axis=-1)
-            filtered = pool[np.min(d, axis=1) > 1e-3]
-            if len(filtered):
-                pool = filtered
-            # per-metric fantasy outcomes, conditioned with rank-1 appends;
-            # ONE eps draw shared across metrics keeps the fantasies
-            # consistent (a lucky pending trial is lucky on every metric)
-            eps = rng.randn(self._n_fantasies, n_pend).mean(axis=0)
-            for post in posts:
-                mean_p, std_p = post.query(fantasy_x)
-                for px, py in zip(fantasy_x, mean_p + std_p * eps):
-                    post.append(px, py)
+        with tracing.span("vizier.policy.acquire"):
+            # incumbent frontier + reference point from the OBSERVED (z-scored)
+            # objectives; the pool sharpens around a balanced frontier member
+            front_idx = pareto_frontier_indices(yz)
+            ref = default_reference_point(yz)                     # (k,)
+            front = yz[front_idx]
+            incumbent = x[front_idx[int(np.argmax(front.sum(axis=1)))]]
+            pool = self._draw_pool(rng, converter.dim, incumbent)
 
-        for post in posts:
-            post.set_pool(pool)
-
-        beta = DEFAULT_UCB_BETA
-        picks: List[np.ndarray] = []
-        picked_idx: List[int] = []
-        u = np.empty((k, len(pool)), np.float64)
-        for b in range(request.count):
-            # random positive scalarization weights per batch member: each
-            # member chases a different frontier direction
-            w = rng.rand(k) + 1e-3
-            w = w / w.sum()
-            for j, post in enumerate(posts):
-                mean, std = post.pool_mean_std()   # fused, one sync/metric
-                u[j] = mean + beta * std
-            t = (u - ref[:, None]) / w[:, None]
-            scores = np.min(t, axis=0) + HV_AUGMENT * np.mean(t, axis=0)
-            scores[picked_idx] = -np.inf
-            i = int(np.argmax(scores))
-            picks.append(pool[i])
-            picked_idx.append(i)
-            if b + 1 < request.count:
-                # fantasize the member at its posterior mean on EVERY metric
+            fantasize = fantasy_x is not None and n_pend > 0 and (
+                config.observation_noise != ObservationNoise.HIGH
+            )
+            if fantasize:
+                d = np.linalg.norm(pool[:, None, :] - fantasy_x[None], axis=-1)
+                filtered = pool[np.min(d, axis=1) > 1e-3]
+                if len(filtered):
+                    pool = filtered
+                # per-metric fantasy outcomes, conditioned with rank-1 appends;
+                # ONE eps draw shared across metrics keeps the fantasies
+                # consistent (a lucky pending trial is lucky on every metric)
+                eps = rng.randn(self._n_fantasies, n_pend).mean(axis=0)
                 for post in posts:
-                    post.append_pool_member(i)
-        suggestions = [
-            TrialSuggestion(parameters=converter.to_parameters(p[None, :])[0])
-            for p in picks
-        ]
+                    mean_p, std_p = post.query(fantasy_x)
+                    for px, py in zip(fantasy_x, mean_p + std_p * eps):
+                        post.append(px, py)
+
+            for post in posts:
+                post.set_pool(pool)
+
+            beta = DEFAULT_UCB_BETA
+            picks: List[np.ndarray] = []
+            picked_idx: List[int] = []
+            u = np.empty((k, len(pool)), np.float64)
+            for b in range(request.count):
+                # random positive scalarization weights per batch member: each
+                # member chases a different frontier direction
+                w = rng.rand(k) + 1e-3
+                w = w / w.sum()
+                for j, post in enumerate(posts):
+                    mean, std = post.pool_mean_std()   # fused, one sync/metric
+                    u[j] = mean + beta * std
+                t = (u - ref[:, None]) / w[:, None]
+                scores = np.min(t, axis=0) + HV_AUGMENT * np.mean(t, axis=0)
+                scores[picked_idx] = -np.inf
+                i = int(np.argmax(scores))
+                picks.append(pool[i])
+                picked_idx.append(i)
+                if b + 1 < request.count:
+                    # fantasize the member at its posterior mean on EVERY metric
+                    for post in posts:
+                        post.append_pool_member(i)
+        with tracing.span("vizier.policy.featurize"):
+            suggestions = [
+                TrialSuggestion(
+                    parameters=converter.to_parameters(p[None, :])[0])
+                for p in picks
+            ]
 
         if self._warm_start and fit_info is not None:
             # schema-v4 checkpoint: metric 0's trajectory doubles as the
@@ -1224,8 +1236,7 @@ class GPBanditPolicy(Policy):
                 result=fit_info.results[0], raw=fit_info.raws[0],
                 m=fit_info.ms[0], v=fit_info.vs[0], t=fit_info.t,
                 steps_run=fit_info.steps_run, warm=fit_info.warm,
-                converged=fit_info.converged, diverged=fit_info.diverged,
-                seconds=fit_info.seconds)
+                converged=fit_info.converged, diverged=fit_info.diverged)
             delta = MetadataDelta()
             store_state(delta, PolicyState.from_fit(
                 info0, dim=converter.dim, num_trials=n,

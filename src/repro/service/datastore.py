@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Tuple
 
 import msgpack
 
+from repro import tracing
 from repro.core.metadata import Metadata
 from repro.service._lockwitness import make_rlock
 from repro.core.study import Study, StudyState, Trial, TrialState
@@ -54,6 +55,23 @@ class DatastoreBusyError(Exception):
     """
 
     code = 14  # StatusCode.UNAVAILABLE (duck-typed; storage stays below rpc)
+
+
+def _decode_trials(blobs_by_study: Dict[str, list]) -> Dict[str, List[Trial]]:
+    """Stored trial blobs -> Trials, as one ``vizier.datastore.decode`` span."""
+    with tracing.span("vizier.datastore.decode",
+                      trials=sum(map(len, blobs_by_study.values()))):
+        return {name: [Trial.from_proto(msgpack.unpackb(b, raw=False))
+                       for b in blobs]
+                for name, blobs in blobs_by_study.items()}
+
+
+def _decode_raw(blobs_by_study: Dict[str, list]) -> Dict[str, list]:
+    """Stored trial blobs -> wire protos (no Trial objects)."""
+    with tracing.span("vizier.datastore.decode",
+                      trials=sum(map(len, blobs_by_study.values()))):
+        return {name: [msgpack.unpackb(b, raw=False) for b in blobs]
+                for name, blobs in blobs_by_study.items()}
 
 
 class Datastore:
@@ -524,6 +542,17 @@ class SQLiteDatastore(Datastore):
     def study_transaction(self, study_name: str):
         return self._txn()
 
+    @contextlib.contextmanager
+    def _reading(self):
+        """The connection lock for one read: a ``vizier.datastore.query``
+        span over the execute and fetch, whose ``vizier.datastore.lock.wait``
+        child is the wait for the lock."""
+        with tracing.span("vizier.datastore.query"), \
+                tracing.span("vizier.datastore.lock.wait") as wait, \
+                self._lock:
+            wait.end()
+            yield
+
     # studies --------------------------------------------------------------------
     def create_study(self, study: Study) -> str:
         blob = msgpack.packb(study.to_proto(), use_bin_type=True)
@@ -537,16 +566,17 @@ class SQLiteDatastore(Datastore):
         return study.name
 
     def get_study(self, study_name: str) -> Study:
-        with self._lock:
+        with self._reading():
             row = self._conn.execute(
                 "SELECT proto FROM studies WHERE name = ?", (study_name,)
             ).fetchone()
         if row is None:
             raise NotFoundError(study_name)
-        return Study.from_proto(msgpack.unpackb(row[0], raw=False))
+        with tracing.span("vizier.datastore.decode"):
+            return Study.from_proto(msgpack.unpackb(row[0], raw=False))
 
     def list_studies(self, owner_prefix: str = "") -> List[Study]:
-        with self._lock:
+        with self._reading():
             rows = self._conn.execute(
                 "SELECT proto FROM studies WHERE name LIKE ? ORDER BY name",
                 (owner_prefix + "%",),
@@ -597,14 +627,14 @@ class SQLiteDatastore(Datastore):
         return trial
 
     def get_trial(self, study_name: str, trial_id: int) -> Trial:
-        with self._lock:
+        with self._reading():
             row = self._conn.execute(
                 "SELECT proto FROM trials WHERE study_name = ? AND trial_id = ?",
                 (study_name, trial_id),
             ).fetchone()
         if row is None:
             raise NotFoundError(f"{study_name}/trials/{trial_id}")
-        return Trial.from_proto(msgpack.unpackb(row[0], raw=False))
+        return _decode_trials({study_name: [row[0]]})[study_name][0]
 
     def list_trials(self, study_name, *, states=None, client_id=None, min_trial_id=None):
         query = "SELECT proto FROM trials WHERE study_name = ?"
@@ -620,14 +650,14 @@ class SQLiteDatastore(Datastore):
             query += " AND trial_id >= ?"
             args.append(min_trial_id)
         query += " ORDER BY trial_id"
-        with self._lock:
+        with self._reading():
             exists = self._conn.execute(
                 "SELECT 1 FROM studies WHERE name = ?", (study_name,)
             ).fetchone()
             if exists is None:
                 raise NotFoundError(study_name)
             rows = self._conn.execute(query, args).fetchall()
-        return [Trial.from_proto(msgpack.unpackb(r[0], raw=False)) for r in rows]
+        return _decode_trials({study_name: [r[0] for r in rows]})[study_name]
 
     def update_trial(self, study_name: str, trial: Trial) -> None:
         trial.study_name = study_name
@@ -651,7 +681,7 @@ class SQLiteDatastore(Datastore):
                 raise NotFoundError(f"{study_name}/trials/{trial_id}")
 
     def max_trial_id(self, study_name: str) -> int:
-        with self._lock:
+        with self._reading():
             exists = self._conn.execute(
                 "SELECT 1 FROM studies WHERE name = ?", (study_name,)
             ).fetchone()
@@ -682,7 +712,7 @@ class SQLiteDatastore(Datastore):
             query += f" AND state IN ({smarks})"
             args += [s.value for s in states]
         query += " ORDER BY study_name, trial_id"
-        with self._lock:
+        with self._reading():
             known = {
                 r[0]
                 for r in self._conn.execute(
@@ -705,19 +735,11 @@ class SQLiteDatastore(Datastore):
         return out
 
     def list_trials_multi(self, study_names, *, states=None):
-        return {
-            name: [Trial.from_proto(msgpack.unpackb(blob, raw=False))
-                   for blob in blobs]
-            for name, blobs in self._fetch_trial_blobs_multi(
-                study_names, states).items()
-        }
+        return _decode_trials(
+            self._fetch_trial_blobs_multi(study_names, states))
 
     def list_trials_multi_raw(self, study_names, *, states=None):
-        return {
-            name: [msgpack.unpackb(blob, raw=False) for blob in blobs]
-            for name, blobs in self._fetch_trial_blobs_multi(
-                study_names, states).items()
-        }
+        return _decode_raw(self._fetch_trial_blobs_multi(study_names, states))
 
     # metadata ----------------------------------------------------------------
     def update_study_metadata(self, study_name: str, metadata: Metadata) -> None:
@@ -753,7 +775,7 @@ class SQLiteDatastore(Datastore):
             )
 
     def get_operation(self, op_name: str) -> dict:
-        with self._lock:
+        with self._reading():
             row = self._conn.execute(
                 "SELECT proto FROM operations WHERE name = ?", (op_name,)
             ).fetchone()
@@ -770,7 +792,7 @@ class SQLiteDatastore(Datastore):
         if only_pending:
             query += " AND done = 0"
         query += " ORDER BY create_time"
-        with self._lock:
+        with self._reading():
             rows = self._conn.execute(query, args).fetchall()
         return [msgpack.unpackb(r[0], raw=False) for r in rows]
 
@@ -899,17 +921,10 @@ class ShardedSqliteDatastore(Datastore):
         return {name: merged[name] for name in study_names}
 
     def list_trials_multi(self, study_names, *, states=None):
-        return {
-            name: [Trial.from_proto(msgpack.unpackb(blob, raw=False))
-                   for blob in blobs]
-            for name, blobs in self._multi_blobs(study_names, states).items()
-        }
+        return _decode_trials(self._multi_blobs(study_names, states))
 
     def list_trials_multi_raw(self, study_names, *, states=None):
-        return {
-            name: [msgpack.unpackb(blob, raw=False) for blob in blobs]
-            for name, blobs in self._multi_blobs(study_names, states).items()
-        }
+        return _decode_raw(self._multi_blobs(study_names, states))
 
     # metadata ----------------------------------------------------------------
     def update_study_metadata(self, study_name: str, metadata: Metadata) -> None:

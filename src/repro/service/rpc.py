@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, Optional
 
 import msgpack
 
+from repro import tracing
 from repro.service import chaos
 from repro.service._lockwitness import make_lock
 
@@ -368,6 +369,10 @@ class RpcClient:
             "params": params,
             "deadline_ms": int(timeout * 1000),
         }
+        with tracing.span("vizier.rpc.call", method=method, rid=request["id"]):
+            return self._call(method, request, deadline)
+
+    def _call(self, method: str, request: dict, deadline: float) -> Any:
         attempt = 0
         while True:
             remaining = deadline - time.monotonic()
@@ -585,6 +590,11 @@ class Servicer:
                 "ok": False,
                 "error": {"code": StatusCode.UNIMPLEMENTED, "message": f"no method {method!r}"},
             }
+        with tracing.span("vizier.rpc.dispatch", method=method, rid=rid):
+            return self._handle(fn, method, rid, request)
+
+    @staticmethod
+    def _handle(fn, method: str, rid, request: dict) -> dict:
         try:
             result = fn(request.get("params") or {})
             return {"id": rid, "ok": True, "result": result}

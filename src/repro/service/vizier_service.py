@@ -42,6 +42,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
+from repro import tracing
 from repro.core.metadata import Metadata, MetadataDelta, Namespace
 from repro.core.pareto import pareto_frontier_indices
 from repro.core.study import (
@@ -434,11 +435,21 @@ class VizierService(Servicer):
 
         Fast paths 1-4 return an op that is already done (or already pending
         elsewhere); only path 5 needs a Pythia dispatch. Caller must hold no
-        locks; this takes the study lock itself.
+        locks; this takes the study lock itself. The work is one
+        ``vizier.suggest.prepare`` span, and the op's name becomes the trace
+        id of the spans open on this thread (the RPC dispatch).
         """
+        with tracing.span("vizier.suggest.prepare"):
+            op, needs_run = self._choose_suggest_op(study_name, client_id, count)
+            tracing.bind(op["name"])
+        return op, needs_run
+
+    def _choose_suggest_op(self, study_name: str, client_id: str, count: int):
         study = self._get_study_or_rpc_error(study_name)
 
-        with self._study_lock(study_name):
+        with tracing.span("vizier.lock.wait") as wait, \
+                self._study_lock(study_name):
+            wait.end()
             # 1. study no longer active -> empty, done (client loop terminates)
             if study.state != StudyState.ACTIVE:
                 op = ops_lib.new_suggest_operation(study_name, client_id, count)
@@ -578,7 +589,10 @@ class VizierService(Servicer):
             suggestions, delta = self._pythia.suggest(
                 study, op["suggestion_count"], client_id
             )
-            with self._study_lock(study_name):
+            with tracing.span("vizier.finalize", trace_id=op["name"]), \
+                    tracing.span("vizier.lock.wait") as wait, \
+                    self._study_lock(study_name):
+                wait.end()
                 # one durable unit: delta + trials + the done op commit
                 # together, so a crash mid-finalize rolls back to a cleanly
                 # re-runnable pending op (never trials without their op)
@@ -648,7 +662,11 @@ class VizierService(Servicer):
                 # injected finalize faults fire before the study lock so a
                 # stall here delays, never deadlocks, the finalize path
                 chaos.inject("service.finalize", study=study.name)
-                with self._study_lock(study.name):
+                with tracing.span("vizier.finalize",
+                                  trace_id=tuple(op["name"] for op in group)), \
+                        tracing.span("vizier.lock.wait") as wait, \
+                        self._study_lock(study.name):
+                    wait.end()
                     if op_guard is not None:
                         # zombie-lease finalize races are settled under the
                         # study lock: drop ops whose lease is gone or that a
@@ -715,6 +733,7 @@ class VizierService(Servicer):
         ladder — the response leaves the instant the op finishes.
         """
         name = params["name"]
+        tracing.bind(name)
         timeout = min(float(params.get("timeout_ms", 0)) / 1000.0,
                       self.MAX_WAIT_S)
         try:
@@ -728,7 +747,8 @@ class VizierService(Servicer):
             entry[1] += 1
             event = entry[0]
         try:
-            event.wait(timeout)
+            with tracing.span("vizier.op.wait"):
+                event.wait(timeout)
         finally:
             with self._op_waiters_guard:
                 cur = self._op_waiters.get(name)
@@ -872,7 +892,10 @@ class VizierService(Servicer):
 
     def CompleteTrial(self, params: dict) -> dict:
         study_name, trial_id = self._parse_trial_name(params["name"])
-        with self._study_lock(study_name):
+        with tracing.span("vizier.complete"), \
+                tracing.span("vizier.lock.wait") as wait, \
+                self._study_lock(study_name):
+            wait.end()
             trial = self._complete_trial_locked(study_name, trial_id, params)
         return {"trial": trial.to_proto()}
 
@@ -910,25 +933,28 @@ class VizierService(Servicer):
         """
         trials: List[Optional[dict]] = []
         errors: List[Optional[dict]] = []
-        for r in params.get("requests") or []:
-            try:
-                study_name, trial_id = self._parse_trial_name(r["name"])
-                with self._study_lock(study_name):
-                    trial = self._complete_trial_locked(study_name, trial_id, r)
-                trials.append(trial.to_proto())
-                errors.append(None)
-            except VizierRpcError as e:
-                trials.append(None)
-                errors.append({"code": e.code, "message": e.message})
-            except NotFoundError as e:
-                trials.append(None)
-                errors.append({"code": StatusCode.NOT_FOUND, "message": str(e)})
-            except (KeyError, TypeError, ValueError) as e:
-                trials.append(None)
-                errors.append({
-                    "code": StatusCode.INVALID_ARGUMENT,
-                    "message": f"malformed sub-request: {type(e).__name__}: {e}",
-                })
+        with tracing.span("vizier.complete"):
+            for r in params.get("requests") or []:
+                try:
+                    study_name, trial_id = self._parse_trial_name(r["name"])
+                    with tracing.span("vizier.lock.wait") as wait, \
+                            self._study_lock(study_name):
+                        wait.end()
+                        trial = self._complete_trial_locked(study_name, trial_id, r)
+                    trials.append(trial.to_proto())
+                    errors.append(None)
+                except VizierRpcError as e:
+                    trials.append(None)
+                    errors.append({"code": e.code, "message": e.message})
+                except NotFoundError as e:
+                    trials.append(None)
+                    errors.append({"code": StatusCode.NOT_FOUND, "message": str(e)})
+                except (KeyError, TypeError, ValueError) as e:
+                    trials.append(None)
+                    errors.append({
+                        "code": StatusCode.INVALID_ARGUMENT,
+                        "message": f"malformed sub-request: {type(e).__name__}: {e}",
+                    })
         return {"trials": trials, "errors": errors}
 
     def DeleteTrial(self, params: dict) -> dict:

@@ -37,6 +37,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import tracing
 from repro.service import chaos
 from repro.service import operations as ops_lib
 from repro.service._lockwitness import make_condition
@@ -88,6 +89,9 @@ class ShardedWorkQueue:
         self._shards = [_Shard() for _ in range(n_shards)]
         self._cv = make_condition("ShardedWorkQueue._cv")
         self._closed = False
+        # op name -> when it (re)entered the queue, for its
+        # ``vizier.queue.pending`` interval (ends at the lease grant)
+        self._queued_since: Dict[str, int] = {}
 
     # -- producers -----------------------------------------------------------
     def shard_of(self, study_name: str) -> int:
@@ -98,6 +102,7 @@ class ShardedWorkQueue:
         sid = self.shard_of(op["study_name"])
         with self._cv:
             self._shards[sid].queued.append(op)
+            self._queued_since[op["name"]] = time.perf_counter_ns()
             self._cv.notify_all()
         return sid
 
@@ -118,17 +123,31 @@ class ShardedWorkQueue:
         if shard.lease is not lease:
             return  # already reclaimed / acked
         # front of the queue, original order: re-runs keep arrival fairness
+        now = time.perf_counter_ns()
         for op in reversed(lease.ops):
             shard.queued.appendleft(ops_lib.note_requeued(op))
+            self._queued_since[op["name"]] = now
         shard.lease = None
         shard.generation += 1  # invalidates the dead holder's lease
         self._cv.notify_all()
 
     def lease(self, worker_id: int, timeout: Optional[float] = None
               ) -> Optional[Lease]:
-        """Claim one free shard's whole backlog; None on timeout/close."""
+        """Claim one free shard's whole backlog; None on timeout/close.
+
+        The wait is one ``vizier.lease.wait`` span, which counts the ops
+        a granted lease brings."""
+        with tracing.span("vizier.lease.wait") as wait:
+            granted = self._lease(worker_id, timeout)
+            if granted is not None:
+                wait.add(ops=len(granted.ops))
+        return granted
+
+    def _lease(self, worker_id: int, timeout: Optional[float]
+               ) -> Optional[Lease]:
         deadline = None if timeout is None else time.monotonic() + timeout
         granted: Optional[Lease] = None
+        queued_since: List[Tuple[str, int]] = []
         while granted is None:
             # the wait loop re-acquires the CV each iteration so reclaim
             # warnings flush outside the critical section
@@ -147,6 +166,11 @@ class ShardedWorkQueue:
                             granted = Lease(sid, shard.generation, worker_id,
                                             ops, now + self.lease_timeout)
                             shard.lease = granted
+                            now_ns = time.perf_counter_ns()
+                            queued_since = [
+                                (op["name"],
+                                 self._queued_since.pop(op["name"], now_ns))
+                                for op in ops]
                             break
                     if granted is None:
                         if deadline is not None:
@@ -161,6 +185,8 @@ class ShardedWorkQueue:
                 for desc, n_ops in reclaimed:
                     log.warning("lease %s expired; requeueing %d ops",
                                 desc, n_ops)
+        for name, since in queued_since:
+            tracing.record("vizier.queue.pending", since, trace_id=name)
         # strictly outside the CV: an injected stall or early expiry on this
         # grant must never block the other shards' lease traffic
         chaos.inject("queue.lease", lease=granted)
@@ -278,19 +304,24 @@ class PythiaWorkerPool:
                 continue
             failed = False
             try:
-                # a mid-batch worker kill lands here: killed.set() via the
-                # seam's kill callback, checked before dispatch and by the
-                # op_guard below
-                chaos.inject("worker.batch", worker=wid, lease=lease,
-                             kill=killed.set)
-                # idempotent re-run: skip ops a dead predecessor finished
-                ops = [op for op in lease.ops if not self._already_done(op)]
-                if ops and not killed.is_set():
-                    self._run_batch(
-                        ops,
-                        lambda op: (not killed.is_set()
-                                    and self._queue.lease_valid(lease)),
-                    )
+                names = tuple(op["name"] for op in lease.ops)
+                with tracing.span("vizier.worker.batch",
+                                  trace_id=names) as batch:
+                    # a mid-batch worker kill lands here: killed.set() via
+                    # the seam's kill callback, checked before dispatch and
+                    # by the op_guard below
+                    chaos.inject("worker.batch", worker=wid, lease=lease,
+                                 kill=killed.set)
+                    # idempotent re-run: skip ops a dead predecessor finished
+                    ops = [op for op in lease.ops
+                           if not self._already_done(op)]
+                    batch.add(ops=len(ops))
+                    if ops and not killed.is_set():
+                        self._run_batch(
+                            ops,
+                            lambda op: (not killed.is_set()
+                                        and self._queue.lease_valid(lease)),
+                        )
                 chaos.inject("queue.ack", lease=lease, kill=killed.set)
             except Exception:  # noqa: BLE001 — the runner fails ops itself
                 log.exception("worker %d batch run raised", wid)

@@ -384,7 +384,6 @@ def test_priors_only_suggest_resets_fit_observability():
         count=1))
     assert policy.last_transfer_levels == 1
     assert policy.last_fit_steps == 0
-    assert policy.last_fit_seconds == 0.0
     assert not policy.last_fit_warm
 
 
