@@ -87,7 +87,8 @@ def _gram_pallas_fwd(x1, x2, amplitude, interpret):
 
 def _gram_pallas_bwd(interpret, residuals, g):
     # XLA-only piece: the backward pass is the VJP of the jnp reference, so
-    # the kernel path's gradients are exactly the reference path's.
+    # the kernel path's gradients are exactly the reference path's, and its
+    # contractions run at HIGHEST as the kernel's forward does.
     _, vjp = jax.vjp(ref.matern52_gram, *residuals)
     return vjp(g)
 

@@ -19,9 +19,13 @@ def matern52_gram(x1: jnp.ndarray, x2: jnp.ndarray, amplitude) -> jnp.ndarray:
     """
     x1 = x1.astype(jnp.float32)
     x2 = x2.astype(jnp.float32)
+    # The cross term at full float32, as in the Pallas kernel: the TPU
+    # default is one bf16 pass, and the Pallas Gram's backward is this
+    # function's VJP, so at that precision the GP fit's log_amp gradient was
+    # off by up to 1.6 on a v5e.
     d2 = (
         jnp.sum(x1 * x1, axis=1)[:, None]
-        - 2.0 * x1 @ x2.T
+        - 2.0 * jnp.dot(x1, x2.T, precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(x2 * x2, axis=1)[None, :]
     )
     d2 = jnp.maximum(d2, 0.0)

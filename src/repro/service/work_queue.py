@@ -315,7 +315,8 @@ class PythiaWorkerPool:
                     # idempotent re-run: skip ops a dead predecessor finished
                     ops = [op for op in lease.ops
                            if not self._already_done(op)]
-                    batch.add(ops=len(ops))
+                    batch.add(ops=len(ops), studies=len(
+                        {op["study_name"] for op in ops}))
                     if ops and not killed.is_set():
                         self._run_batch(
                             ops,

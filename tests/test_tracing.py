@@ -192,6 +192,7 @@ def test_one_suggest_op_yields_its_span_tree(served_op):
     pending = _one(spans, "vizier.queue.pending")
     batch = _one(spans, "vizier.worker.batch")
     assert batch.trace_id == (op,) and batch.counts["ops"] == 1
+    assert batch.counts["studies"] == 1
     assert prepare.start_ns <= pending.start_ns <= suggest.end_ns
     assert pending.end_ns <= batch.start_ns
 
@@ -218,3 +219,45 @@ def test_one_suggest_op_yields_its_span_tree(served_op):
     parks = [r for r in spans if r.name == "vizier.op.wait"]
     assert parks
     assert all(any(p.parent_id == w.span_id for w in waits) for p in parks)
+
+
+# ---------------------------------------------------------------------------
+# A worker batch counts the studies its lease brought
+# ---------------------------------------------------------------------------
+
+
+def test_worker_batch_counts_the_distinct_studies_it_serves():
+    """One queue shard holds ops of two studies (three ops): the lease
+    brings all three and the batch records studies=2; a later lease of one
+    study's op records 1."""
+    from repro.service import operations as ops_lib
+    from repro.service.work_queue import PythiaWorkerPool, ShardedWorkQueue
+
+    tag = uuid.uuid4().hex
+    mixed = [ops_lib.new_suggest_operation(f"owners/t/studies/{tag}{s}",
+                                           "c", 1) for s in "aba"]
+    single = [ops_lib.new_suggest_operation(f"owners/t/studies/{tag}c",
+                                            "c", 1)]
+    q = ShardedWorkQueue(1)
+    for op in mixed:             # queued before any worker leases
+        q.enqueue(op)
+
+    def drain():
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline and q.pending_count():
+            time.sleep(0.01)
+        assert q.pending_count() == 0
+
+    pool = PythiaWorkerPool(q, lambda ops, guard: None, lambda op: False,
+                            n_workers=1).start()
+    try:
+        drain()
+        q.enqueue(single[0])
+        drain()
+    finally:
+        pool.shutdown()
+    for ops, studies in ((mixed, 2), (single, 1)):
+        names = tuple(op["name"] for op in ops)
+        batch = _one(_mine(names), "vizier.worker.batch")
+        assert batch.trace_id == names
+        assert batch.counts == {"ops": len(ops), "studies": studies}
